@@ -36,7 +36,9 @@ deprecated one-shot shim over this class (enforced by
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
+import jax
 import numpy as np
 
 from .nom_collectives import _dor_path, plan_transfers
@@ -386,6 +388,7 @@ class NomFabric:
         return order
 
     # -- the synchronous batch path ------------------------------------------
+    @partial(jax.profiler.annotate_function, name="nom.schedule")
     def schedule(self, transfers, cycle: int | None = None,
                  policy: str | None = None):
         """Schedule a batch of bulk transfers concurrently.

@@ -814,6 +814,7 @@ class TdmAllocator:
         return self.allocate_batch(
             [CopyRequest(src, dst, nbytes, max_extra_slots)], cycle)[0]
 
+    @partial(jax.profiler.annotate_function, name="nom.alloc.batch")
     def allocate_batch(self, requests: list, cycle: int) -> list[AllocResult]:
         """Service a batch of pending copy requests concurrently.
 
@@ -906,82 +907,91 @@ class TdmAllocator:
             if pending is not None:
                 self._emit_wave_fused(pending, results, cycle)
                 pending = None
-            states = self._prepare_states(wave, t_readys[lo:hi], window)
-            report.search_rounds += 1
-            report.n_searched += len(wave)
-            if self._last_prepare_backend == "fused":
-                report.fused_waves += 1
-            else:
-                report.host_waves += 1
-            # Pending *run*: consecutive single-slot states whose chosen
-            # (node, port, slot) reservation entries are pairwise
-            # disjoint.  Entry disjointness makes their commits
-            # order-independent and keeps each member's live-table
-            # validation independent of the others' (a commit only writes
-            # its own entries), so the whole run is validated with ONE
-            # vectorized expiry gather and committed with one vectorized
-            # reservation — outcome-identical to committing each
-            # serially.  A state that cannot join (bus route, extra-slot
-            # bundle, entry overlap with a pending member) flushes the
-            # run first, so the serial path always sees exactly the live
-            # table it would have seen.
-            run: list[int] = []
-            run_claims: set = set()  # entry ids of pending members
-            work = list(range(len(wave)))
-            i = 0
-            while True:
-                if i >= len(work):
-                    if not run:
-                        break
-                    redo = self._flush_pending(states, run, wave,
-                                               t_readys[lo:hi], results,
-                                               lo, window, cycle, report)
-                    run = []
-                    run_claims = set()
-                    if redo:
-                        work[i:i] = redo
-                    continue
-                k = work[i]
-                st = states[k]
-                if st.denied:
-                    report.n_denied += 1
-                    results[lo + k] = AllocResult(None, cycle)
-                    i += 1
-                    continue
-                if st.flat is not None and not st.conflict:
-                    if run_claims.isdisjoint(st.flat):
-                        run.append(k)
-                        run_claims |= st.flat
-                        i += 1
-                        continue
-                # k cannot ride the pending run: flush, then retry k (it
-                # may start the next run, or fall through to the serial
-                # path below once the run is empty).
-                if run:
-                    redo = self._flush_pending(states, run, wave,
-                                               t_readys[lo:hi], results,
-                                               lo, window, cycle, report)
-                    run = []
-                    run_claims = set()
-                    if redo:
-                        work[i:i] = redo
-                    continue
-                out = self._commit_prepared(st, window, validate=True)
-                if out is _CONFLICT:
-                    st, out = self._handle_conflict(
-                        wave[k], t_readys[lo + k:lo + k + 1], window,
-                        report)
-                if out is None:
-                    report.n_denied += 1
-                else:
-                    report.n_committed += 1
-                results[lo + k] = AllocResult(out, cycle)
-                i += 1
+            self._commit_wave_states(wave, t_readys[lo:hi], lo, window,
+                                     cycle, results, report)
         if pending is not None:
             self._emit_wave_fused(pending, results, cycle)
         self.last_report = report
         return results
 
+    @partial(jax.profiler.annotate_function, name="nom.alloc.wave")
+    def _commit_wave_states(self, wave: list[CopyRequest], t_w: np.ndarray,
+                            lo: int, window: int, cycle: int, results,
+                            report: BatchReport) -> None:
+        """One wave outside the fused fast path (a wave holding an INIT,
+        a reduce or an extra-slot request, or one the host prepares):
+        prepare a state per request against one snapshot, then commit
+        them in arrival order."""
+        states = self._prepare_states(wave, t_w, window)
+        report.search_rounds += 1
+        report.n_searched += len(wave)
+        if self._last_prepare_backend == "fused":
+            report.fused_waves += 1
+        else:
+            report.host_waves += 1
+        # Pending *run*: consecutive single-slot states whose chosen
+        # (node, port, slot) reservation entries are pairwise
+        # disjoint.  Entry disjointness makes their commits
+        # order-independent and keeps each member's live-table
+        # validation independent of the others' (a commit only writes
+        # its own entries), so the whole run is validated with ONE
+        # vectorized expiry gather and committed with one vectorized
+        # reservation — outcome-identical to committing each
+        # serially.  A state that cannot join (bus route, extra-slot
+        # bundle, entry overlap with a pending member) flushes the
+        # run first, so the serial path always sees exactly the live
+        # table it would have seen.
+        run: list[int] = []
+        run_claims: set = set()  # entry ids of pending members
+        work = list(range(len(wave)))
+        i = 0
+        while True:
+            if i >= len(work):
+                if not run:
+                    break
+                redo = self._flush_pending(states, run, wave, t_w, results,
+                                           lo, window, cycle, report)
+                run = []
+                run_claims = set()
+                if redo:
+                    work[i:i] = redo
+                continue
+            k = work[i]
+            st = states[k]
+            if st.denied:
+                report.n_denied += 1
+                results[lo + k] = AllocResult(None, cycle)
+                i += 1
+                continue
+            if st.flat is not None and not st.conflict:
+                if run_claims.isdisjoint(st.flat):
+                    run.append(k)
+                    run_claims |= st.flat
+                    i += 1
+                    continue
+            # k cannot ride the pending run: flush, then retry k (it
+            # may start the next run, or fall through to the serial
+            # path below once the run is empty).
+            if run:
+                redo = self._flush_pending(states, run, wave, t_w, results,
+                                           lo, window, cycle, report)
+                run = []
+                run_claims = set()
+                if redo:
+                    work[i:i] = redo
+                continue
+            out = self._commit_prepared(st, window, validate=True)
+            if out is _CONFLICT:
+                st, out = self._handle_conflict(wave[k], t_w[k:k + 1],
+                                                window, report)
+            if out is None:
+                report.n_denied += 1
+            else:
+                report.n_committed += 1
+            results[lo + k] = AllocResult(out, cycle)
+            i += 1
+
+    @partial(jax.profiler.annotate_function, name="nom.alloc.research")
     def _handle_conflict(self, req: CopyRequest, t_ready: np.ndarray,
                          window: int, report: BatchReport):
         """Stale-snapshot conflict: re-search ``req`` alone against the
@@ -1081,11 +1091,13 @@ class TdmAllocator:
     # they keep the generic per-state loop.
     _wave_fast: bool = True
 
+    @partial(jax.profiler.annotate_function,
+             name="nom.alloc.fused.dispatch")
     def _dispatch_wave_fused(self, wave: list[CopyRequest],
                              t_w: np.ndarray, window: int):
-        """Launch the fused program for a wave without blocking (JAX
-        async dispatch) — the caller emits the previous wave's circuits
-        while the device searches this one."""
+        """Upload the occupancy and launch the fused program for a wave
+        without blocking (JAX async dispatch) — the fast path emits the
+        previous wave's circuits while the device searches this one."""
         from repro.kernels.slot_alloc import fused as _fused
         B = len(wave)
         srcs = np.fromiter((r.src for r in wave), np.int64, B)
@@ -1095,6 +1107,15 @@ class TdmAllocator:
             mesh=self.mesh, n_slots=self.n_slots,
             kernel=self._fused_kernel)
 
+    @staticmethod
+    @partial(jax.profiler.annotate_function, name="nom.alloc.fused.wait")
+    def _wait_wave_fused(token):
+        """Block on :meth:`_dispatch_wave_fused`'s program and unpack its
+        outputs: the host's wait on the prepare program."""
+        from repro.kernels.slot_alloc import fused as _fused
+        return _fused.fused_prepare_wait(token)
+
+    @partial(jax.profiler.annotate_function, name="nom.alloc.fused.emit")
     def _emit_wave_fused(self, pending, results, cycle: int) -> None:
         """Deferred circuit emission for a fused wave's clean commits:
         pure bookkeeping (no table access), so it runs overlapped with
@@ -1116,6 +1137,8 @@ class TdmAllocator:
                                       hs_l[i][:ln])),
                         distance=dists_l[i], _n_slots_hint=n), cycle)
 
+    @partial(jax.profiler.annotate_function,
+             name="nom.alloc.fused.commit")
     def _commit_wave_fused(self, token, wave: list[CopyRequest],
                            t_w: np.ndarray, lo: int, window: int,
                            cycle: int, results, report: BatchReport):
@@ -1141,10 +1164,9 @@ class TdmAllocator:
         :meth:`_emit_wave_fused` — reservations and conflict results are
         final when this returns, but clean commits' Circuit objects are
         not yet built."""
-        from repro.kernels.slot_alloc import fused as _fused
         n = self.n_slots
         B = len(wave)
-        fp = _fused.fused_prepare_wait(token)
+        fp = self._wait_wave_fused(token)
         self._last_prepare_backend = "fused"
         denied = fp.denied
         if (~denied & ~fp.ok).any():
@@ -1399,14 +1421,10 @@ class TdmAllocator:
         trace-back in a single compiled dispatch), then the same bundle
         assembly as :meth:`_prepare_full` — identical denial semantics,
         extra-slot order, and reservation indices."""
-        from repro.kernels.slot_alloc import fused as _fused
         n = self.n_slots
         B = len(reqs)
-        srcs = np.fromiter((r.src for r in reqs), np.int64, B)
-        dsts = np.fromiter((r.dst for r in reqs), np.int64, B)
-        fp = _fused.fused_prepare(
-            self.table.device_busy_masks(window), srcs, dsts, t_readys,
-            mesh=self.mesh, n_slots=n, kernel=self._fused_kernel)
+        fp = self._wait_wave_fused(
+            self._dispatch_wave_fused(reqs, t_readys, window))
         self._last_prepare_backend = "fused"
         denied, arr, ok = fp.denied, fp.arr, fp.ok
         want = np.fromiter(
@@ -1419,6 +1437,8 @@ class TdmAllocator:
             slots_ix = np.arange(n, dtype=np.int64)
             er, ec = np.nonzero(fp.free & (want > 0)[:, None]
                                 & (slots_ix[None, :] != arr[:, None]))
+            srcs = np.fromiter((r.src for r in reqs), np.int64, B)
+            dsts = np.fromiter((r.dst for r in reqs), np.int64, B)
             occ = self.table._ports.masks_at(window)
             extra_hops, extra_ok = _traceback_jobs(
                 fp.vecs_np(), er, occ, self.mesh, n, srcs[er], dsts[er], ec)

@@ -27,6 +27,7 @@ and ``docs/fabric.md``.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -758,6 +759,7 @@ class Engine:
         self.n_migrations += 1
         return self._schedule_batch(reqs)
 
+    @partial(jax.profiler.annotate_function, name="engine.schedule_tick")
     def schedule_tick(self, tenants: list[str] | None = None
                       ) -> ScheduleReport | None:
         """Schedule one step's transfer set for the named tenants (default:
@@ -849,38 +851,46 @@ class Engine:
         :meth:`transfer_telemetry`.
         """
         b, plen = prompt.shape
-        caches = self.model.init_caches(b, self.max_len)
-        name = None
-        if self.fabric is not None:
-            name = tenant or f"gen{self._gen_seq}"
-            self._gen_seq += 1
-            # queue=False: generate cannot return for a deferred
-            # admission, so "queue" mode degrades to shed-on-full here.
-            if self.open_tenant(name, b, queue=False) is None:
-                name = None
-        logits = None
-        try:
-            # Prefill token by token (one compiled program for both phases).
-            for i in range(plen):
-                logits, caches = self._step(params, prompt[:, i:i + 1],
-                                            caches, jnp.int32(i), memory)
-                if name is not None:
-                    self.schedule_tick([name])
-            out = [prompt]
-            tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-            out.append(tok)
-            for i in range(plen, plen + n_new - 1):
-                logits, caches = self._step(params, tok, caches,
-                                            jnp.int32(i), memory)
-                tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-                out.append(tok)
-                if name is not None:
-                    self.schedule_tick([name])
-        finally:
-            if name is not None and (name in self._tenants
-                                     or name in self._reclaimed):
-                self.close_tenant(name)
-        return jnp.concatenate(out, axis=1)
+        label = tenant or f"gen{self._gen_seq}"
+        self._gen_seq += 1
+        name = None if self.fabric is None else label
+        span = partial(jax.profiler.TraceAnnotation, tenant=label)
+        with span("engine.generate"):
+            caches = self.model.init_caches(b, self.max_len)
+            if name is not None:
+                # queue=False: generate cannot return for a deferred
+                # admission, so "queue" mode degrades to shed-on-full here.
+                with span("engine.open_tenant"):
+                    if self.open_tenant(name, b, queue=False) is None:
+                        name = None
+            logits = None
+            try:
+                # Prefill token by token (one compiled program for both
+                # phases); the span ends once the first token is dispatched.
+                with span("engine.prefill", prompt_len=plen):
+                    for i in range(plen):
+                        logits, caches = self._step(
+                            params, prompt[:, i:i + 1], caches, jnp.int32(i),
+                            memory)
+                        if name is not None:
+                            self.schedule_tick([name])
+                    tok = jnp.argmax(logits[:, -1:],
+                                     axis=-1).astype(jnp.int32)
+                out = [prompt, tok]
+                for i in range(plen, plen + n_new - 1):
+                    logits, caches = self._step(params, tok, caches,
+                                                jnp.int32(i), memory)
+                    tok = jnp.argmax(logits[:, -1:],
+                                     axis=-1).astype(jnp.int32)
+                    out.append(tok)
+                    if name is not None:
+                        self.schedule_tick([name])
+            finally:
+                if name is not None and (name in self._tenants
+                                         or name in self._reclaimed):
+                    with span("engine.close_tenant"):
+                        self.close_tenant(name)
+            return jnp.concatenate(out, axis=1)
 
     def transfer_telemetry(self) -> dict:
         """Aggregate transfer-scheduling stats over the engine's lifetime.
