@@ -65,6 +65,9 @@ def _mask(q_pos, k_pos, cfg: AttentionConfig, prefix_len=None):
 class Attention:
     cfg: AttentionConfig
 
+    # q_norm / k_norm are read in float32 and stay out.
+    compute_leaves = ("wq", "wk", "wv", "wo", "bq", "bk", "bv")
+
     # -- params ---------------------------------------------------------------
     def init(self, key) -> Params:
         c = self.cfg

@@ -19,8 +19,8 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig, LayerKind
 
 from .attention import Attention, AttentionConfig
-from .common import (AxesTree, LayerNorm, Params, RMSNorm, prepend_layer_axis,
-                     stack_layers)
+from .common import (COMPUTE_DTYPE, AxesTree, LayerNorm, Params, RMSNorm,
+                     prepend_layer_axis, stack_layers)
 from .mlp import MLP, MLPConfig
 from .moe import MoE, MoEConfig
 from .rglru import RecurrentBlock, RGLRUConfig
@@ -115,6 +115,21 @@ class DecoderLayer:
             if ffn is not None:
                 a["ln2_post"] = n.axes()
         return a
+
+    def serving_params(self, p: Params) -> Params:
+        """``p`` with the ``compute_leaves`` of its mixer, cross-attention
+        and ffn cast to ``COMPUTE_DTYPE``.  A module names as
+        ``compute_leaves`` the leaves it reads only through a cast to the
+        activations' dtype, so the cast copy gives the same result and
+        spares the step the cast.  Norms, and the leaves of modules that
+        name none, stay as they are."""
+        out = dict(p)
+        for key, mod in zip(("mixer", "ffn", "cross"), self._mods()):
+            names = getattr(mod, "compute_leaves", ())
+            if key in p and names:
+                out[key] = {k: v.astype(COMPUTE_DTYPE) if k in names else v
+                            for k, v in p[key].items()}
+        return out
 
     # -- full-sequence (train / prefill) -------------------------------------
     def apply(self, p: Params, x, *, positions=None, memory=None,
@@ -242,6 +257,16 @@ class LayerStack:
         group = {f"l{i}": layers[i].axes() for i in range(self.group_size)}
         return {"groups": prepend_layer_axis(group) if self.n_groups else {},
                 "tail": {f"l{i}": layers[i].axes()
+                         for i in range(self.n_tail)}}
+
+    def serving_params(self, p: Params) -> Params:
+        """Every layer's ``DecoderLayer.serving_params``; a stacked leaf
+        casts as one array."""
+        layers = self._layers()
+        groups = ({f"l{i}": layers[i].serving_params(p["groups"][f"l{i}"])
+                   for i in range(self.group_size)} if self.n_groups else {})
+        return {"groups": groups,
+                "tail": {f"l{i}": layers[i].serving_params(p["tail"][f"l{i}"])
                          for i in range(self.n_tail)}}
 
     # -- forward -------------------------------------------------------------------

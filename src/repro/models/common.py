@@ -106,7 +106,9 @@ class Embed:
         return {"embedding": ("vocab", "embed")}
 
     def apply(self, p: Params, ids: jax.Array) -> jax.Array:
-        x = jnp.take(p["embedding"].astype(COMPUTE_DTYPE), ids, axis=0)
+        # Gather, then cast the rows taken: the same values as casting the
+        # table first, without converting the whole table every call.
+        x = jnp.take(p["embedding"], ids, axis=0).astype(COMPUTE_DTYPE)
         if self.scale_by_sqrt_dim:
             x = x * jnp.asarray(np.sqrt(self.dim), COMPUTE_DTYPE)
         return x
@@ -127,6 +129,8 @@ class Dense:
     use_bias: bool = False
     in_axis_name: str | None = "embed"
     out_axis_name: str | None = "mlp"
+
+    compute_leaves = ("kernel", "bias")
 
     def init(self, key) -> Params:
         p = {"kernel": dense_init(key, (self.in_dim, self.out_dim))}

@@ -78,6 +78,13 @@ class CausalLM:
         return self._logits(p, x), aux
 
     # -- decode -------------------------------------------------------------------
+    def serving_params(self, p: Params) -> Params:
+        """The weights ``decode_step`` serves from: the layer stack's
+        compute-dtype leaves already cast (``LayerStack.serving_params``);
+        the embedding, the final norm and the float32 head as they are.
+        ``decode_step`` computes the same with either tree."""
+        return {**p, "stack": self.stack.serving_params(p["stack"])}
+
     def init_caches(self, batch: int, max_len: int):
         return self.stack.init_caches(batch, max_len)
 
@@ -141,6 +148,11 @@ class EncDecLM:
         x = _norm(c).apply(p["final_norm"], x)
         logits = Embed(c.padded_vocab, c.d_model).attend(p["embed"], x)
         return logits, aux
+
+    def serving_params(self, p: Params) -> Params:
+        """As ``CausalLM.serving_params``, for the decoder stack that
+        ``decode_step`` runs."""
+        return {**p, "decoder": self.decoder.serving_params(p["decoder"])}
 
     def init_caches(self, batch: int, max_len: int):
         return self.decoder.init_caches(batch, max_len)

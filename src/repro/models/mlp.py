@@ -28,6 +28,8 @@ def _act(name: str):
 class MLP:
     cfg: MLPConfig
 
+    compute_leaves = ("w_up", "w_gate", "w_down", "b_up", "b_down")
+
     def init(self, key) -> Params:
         c = self.cfg
         k1, k2, k3 = jax.random.split(key, 3)

@@ -306,6 +306,11 @@ class Engine:
         self.n_deadline_misses = 0
         self.n_admitted_late = 0
         self.peak_tenants = 0
+        # The decode program's weights (see _serving_params): the caller's
+        # leaves and treedef, held so their ids stay theirs, and the copy.
+        self._served: tuple | None = None
+        self.n_weight_preparations = 0
+        self.n_weight_reuses = 0
 
     def _decode_one(self, params, token, caches, pos, memory=None):
         if isinstance(self.model, EncDecLM):
@@ -315,6 +320,24 @@ class Engine:
             logits, caches = self.model.decode_step(params, token, caches,
                                                     pos)
         return logits, caches
+
+    def _serving_params(self, params, span):
+        """``model.serving_params(params)``, built once per set of weight
+        arrays and reused while later calls pass the same leaves (jax
+        arrays are immutable, so identity keys them).  One copy at a time:
+        other arrays replace it."""
+        leaves, tree = jax.tree.flatten(params)
+        if self._served is not None:
+            held, held_tree, served = self._served
+            if held_tree == tree and all(a is b for a, b in zip(held, leaves)):
+                self.n_weight_reuses += 1
+                return served
+        self._served = None
+        with span("engine.prepare_weights"):
+            served = self.model.serving_params(params)
+        self._served = (leaves, tree, served)
+        self.n_weight_preparations += 1
+        return served
 
     # -- cache leaf inventory ----------------------------------------------
     def _leaf_specs(self, batch: int) -> list[LeafSpec]:
@@ -849,6 +872,9 @@ class Engine:
         ``admission="raise"`` the exhaustion raises instead.  Telemetry
         lands on ``self.reports`` / ``self.last_report`` /
         :meth:`transfer_telemetry`.
+
+        The step runs on ``model.serving_params(params)``: the first call
+        with these arrays builds it, later ones reuse it.
         """
         b, plen = prompt.shape
         label = tenant or f"gen{self._gen_seq}"
@@ -856,6 +882,7 @@ class Engine:
         name = None if self.fabric is None else label
         span = partial(jax.profiler.TraceAnnotation, tenant=label)
         with span("engine.generate"):
+            params = self._serving_params(params, span)
             caches = self.model.init_caches(b, self.max_len)
             if name is not None:
                 # queue=False: generate cannot return for a deferred
@@ -910,7 +937,9 @@ class Engine:
         late-admitted ticketed streams — / ``admitted_late`` /
         ``admission_wait_p50`` / ``admission_wait_p99`` — admission-wait
         quantiles in engine ticks — / ``admission_classes`` — per-
-        service-class outcome counts)."""
+        service-class outcome counts), and the serving copy of the weights
+        (``weight_preparations`` — copies built — / ``weight_reuses`` —
+        requests that found theirs built)."""
         if not self.n_sched_steps:
             return {}
         agg = self.last_report
@@ -944,4 +973,6 @@ class Engine:
             "admission_wait_p99": self.tenant_queue.wait_quantile(0.99),
             "admission_classes": {k: dict(v) for k, v
                                   in sorted(self._class_stats.items())},
+            "weight_preparations": self.n_weight_preparations,
+            "weight_reuses": self.n_weight_reuses,
         }
