@@ -1,9 +1,9 @@
-"""Architecture registry: the 10 assigned architectures + the paper's own
+"""Architecture registry: the assigned architectures + the paper's own
 NoM memory-system config.  ``--arch <id>`` resolves through ARCHS."""
 from __future__ import annotations
 
-from . import (command_r_plus, gemma3_27b, mamba2_130m, paligemma_3b,
-               phi35_moe, qwen15_4b, qwen25_32b, qwen3_moe,
+from . import (command_r_plus, deepseek_v2, gemma3_27b, mamba2_130m,
+               paligemma_3b, phi35_moe, qwen15_4b, qwen25_32b, qwen3_moe,
                recurrentgemma_9b, whisper_small)
 from .base import ArchConfig, LayerKind
 
@@ -18,6 +18,7 @@ ARCHS = {
     "command-r-plus-104b": command_r_plus,
     "gemma3-27b": gemma3_27b,
     "paligemma-3b": paligemma_3b,
+    "deepseek-v2-236b-a21b": deepseek_v2,
 }
 
 # The four assigned input shapes (seq_len, global_batch, kind).

@@ -1,4 +1,4 @@
-"""Architecture config schema shared by all 10 assigned architectures."""
+"""Architecture config schema shared by every registered architecture."""
 from __future__ import annotations
 
 import dataclasses
@@ -7,10 +7,21 @@ import dataclasses
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
     """One position in the repeating layer pattern."""
-    mixer: str = "attn"          # attn | ssm | rglru
+    mixer: str = "attn"          # attn | mla | ssm | rglru
     window: int | None = None    # sliding-window size for local attention
     rope_theta: float | None = None   # override per layer kind (gemma3 global)
     ffn: str = "mlp"             # mlp | moe | none
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (DeepSeek-V2's ``rope_scaling``, type "yarn")."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,6 +44,15 @@ class ArchConfig:
     rope_theta: float = 10_000.0
     use_rope: bool = True
 
+    # multi-head latent attention (mixer "mla"): low-rank query and
+    # key/value projections, a rotary part shared by every head
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    yarn: Yarn | None = None
+
     # norms / activations
     norm_type: str = "rms"       # rms | layer
     zero_centered_norm: bool = False   # gemma (1+g) RMSNorm
@@ -50,7 +70,17 @@ class ArchConfig:
     top_k: int = 0
     moe_dff: int = 0
     norm_topk: bool = True
-    moe_dispatch: str = "nom"    # nom | xla | einsum
+    moe_dispatch: str = "nom"    # nom | xla | einsum | held
+    # "held": the layer holds experts [first, stop) of the n_experts it
+    # routes over (None: all of them) and computes their part alone
+    experts_held: tuple[int, int] | None = None
+    n_shared_experts: int = 0
+    # group-limited greedy routing: top-k among the topk_group groups of
+    # experts whose best score is highest (1 and 1: plain top-k)
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling: float = 1.0
+    first_k_dense: int = 0       # leading layers whose ffn is the dense MLP
 
     # SSM / RG-LRU
     ssm_state: int = 0
@@ -91,12 +121,22 @@ class ArchConfig:
         hd = self.resolved_head_dim
         attn = self.d_model * hd * (2 * self.n_heads + 2 * self.n_kv)
         mlp = self.d_model * self.d_ff * (3 if self.gated_mlp else 2)
-        moe = (self.d_model * self.moe_dff * 3 * self.n_experts
+        moe = (self.d_model * self.moe_dff * 3
+               * (self.n_experts + self.n_shared_experts)
                + self.d_model * self.n_experts) if self.n_experts else 0
+        mla = (self.d_model * (self.q_lora_rank + self.kv_lora_rank
+                               + self.qk_rope_head_dim)
+               + self.q_lora_rank * self.n_heads
+               * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+               + self.kv_lora_rank * self.n_heads
+               * (self.qk_nope_head_dim + self.v_head_dim)
+               + self.n_heads * self.v_head_dim * self.d_model)
         per_layer = 0
         for k in self.pattern:
             if k.mixer == "attn":
                 per_layer += attn
+            elif k.mixer == "mla":
+                per_layer += mla
             elif k.mixer == "ssm":
                 d_in = 2 * self.d_model
                 per_layer += self.d_model * (2 * d_in + 2 * self.ssm_state

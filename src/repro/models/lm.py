@@ -91,15 +91,29 @@ class CausalLM:
     def cache_axes(self):
         return self.stack.cache_axes()
 
-    def decode_step(self, p: Params, token: jax.Array, caches,
-                    pos: jax.Array):
-        """token: (B, 1) -> (logits (B,1,V) fp32, new caches)."""
+    def init_counts(self) -> dict:
+        """Zeroed counters for ``decode_step_counted``: one int32 scalar
+        per name a layer counts (an empty dict for most models)."""
+        return {k: jnp.zeros((), jnp.int32) for k in self.stack.counters}
+
+    def decode_step_counted(self, p: Params, token: jax.Array, caches,
+                            pos: jax.Array, counts: dict):
+        """``decode_step``, and ``counts`` with the step's counters added
+        (the expert layers' routed pairs, held here and elsewhere)."""
         c = self.cfg
         emb = Embed(c.padded_vocab, c.d_model, scale_by_sqrt_dim=c.scale_embed_sqrt_d)
         x = emb.apply(p["embed"], token)
-        x, caches = self.stack.decode(p["stack"], x, caches, pos)
+        x, caches, n = self.stack.decode(p["stack"], x, caches, pos)
         x = _norm(c).apply(p["final_norm"], x)
-        return self._logits(p, x), caches
+        return self._logits(p, x), caches, {k: counts[k] + n[k]
+                                            for k in counts}
+
+    def decode_step(self, p: Params, token: jax.Array, caches,
+                    pos: jax.Array):
+        """token: (B, 1) -> (logits (B,1,V) fp32, new caches)."""
+        logits, caches, _ = self.decode_step_counted(p, token, caches, pos,
+                                                     {})
+        return logits, caches
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,8 +178,8 @@ class EncDecLM:
                     pos: jax.Array, memory: jax.Array):
         c = self.cfg
         x = Embed(c.padded_vocab, c.d_model).apply(p["embed"], token)
-        x, caches = self.decoder.decode(p["decoder"], x, caches, pos,
-                                        memory=memory.astype(x.dtype))
+        x, caches, _ = self.decoder.decode(p["decoder"], x, caches, pos,
+                                           memory=memory.astype(x.dtype))
         x = _norm(c).apply(p["final_norm"], x)
         return Embed(c.padded_vocab, c.d_model).attend(p["embed"], x), caches
 

@@ -500,3 +500,136 @@ class MoE:
                                    d=x.shape[-1],
                                    itemsize=jnp.dtype(x.dtype).itemsize)
         return y, aux
+
+
+# ---------------------------------------------------------------------------
+# Held experts: the layer one expert-parallel rank runs, dropless
+# ---------------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class HeldMoEConfig:
+    d_model: int
+    d_ff: int
+    n_experts: int                  # the router's width: every expert
+    top_k: int
+    held: tuple[int, int]           # experts [first, stop) held here
+    n_shared: int = 0               # shared experts, width n_shared * d_ff
+    n_group: int = 1                # group-limited greedy routing
+    topk_group: int = 1
+    routed_scaling: float = 1.0
+    norm_topk: bool = True
+
+    @property
+    def n_held(self) -> int:
+        return self.held[1] - self.held[0]
+
+
+def route(logits: jax.Array, c: HeldMoEConfig):
+    """Router of HF ``MoEGate`` (``group_limited_greedy``): softmax
+    scores over every expert, the top-k among the ``topk_group`` groups
+    whose best score is highest (one group of all: plain top-k); weights
+    renormalized (``norm_topk``) or scaled by ``routed_scaling``.
+    logits: (T, E) float32 -> (weights (T, k) float32, experts (T, k))."""
+    scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    t = scores.shape[0]
+    groups = scores.reshape(t, c.n_group, -1)
+    _, top_groups = lax.top_k(groups.max(-1), c.topk_group)
+    keep = jax.nn.one_hot(top_groups, c.n_group, dtype=bool).any(1)
+    scores = jnp.where(keep[:, :, None], groups, 0.0).reshape(t, -1)
+    w, e = lax.top_k(scores, c.top_k)
+    if c.norm_topk:
+        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-20)
+    else:
+        w = w * c.routed_scaling
+    return w, e
+
+
+@dataclasses.dataclass(frozen=True)
+class HeldMoE:
+    """An expert layer told which experts it holds (``cfg.held``).  It
+    routes every token over all ``n_experts``, computes the (token, expert)
+    pairs that land on its own experts with no capacity cap, leaves out the
+    pairs that land on experts held elsewhere, and adds the shared experts
+    for every token.  On one chip it runs without the exchange: its result
+    is the part of the layer its experts give, plus the shared experts.
+
+    ``apply`` returns ``(y, counts)``: the pairs routed to held experts and
+    to the others, as int32 scalars named by ``counters``."""
+    cfg: HeldMoEConfig
+
+    counters = ("routed_held", "routed_elsewhere")
+    # The expert weights, read in the activations' dtype.  The router is
+    # not one: as HF ``MoEGate`` does, it takes its logits from float32
+    # operands, since rounding there flips discrete routing decisions.
+    compute_leaves = ("w_gate", "w_up", "w_down",
+                      "shared_gate", "shared_up", "shared_down")
+
+    def init(self, key) -> Params:
+        c = self.cfg
+        keys = jax.random.split(key, 7)
+        p = {"router": dense_init(keys[0], (c.d_model, c.n_experts)),
+             "w_gate": dense_init(keys[1], (c.n_held, c.d_model, c.d_ff),
+                                  in_axis=1),
+             "w_up": dense_init(keys[2], (c.n_held, c.d_model, c.d_ff),
+                                in_axis=1),
+             "w_down": dense_init(keys[3], (c.n_held, c.d_ff, c.d_model),
+                                  in_axis=1)}
+        if c.n_shared:
+            f = c.n_shared * c.d_ff
+            p["shared_gate"] = dense_init(keys[4], (c.d_model, f))
+            p["shared_up"] = dense_init(keys[5], (c.d_model, f))
+            p["shared_down"] = dense_init(keys[6], (f, c.d_model))
+        return p
+
+    def axes(self) -> AxesTree:
+        a = {"router": ("embed", None),
+             "w_gate": ("experts", "embed", "mlp"),
+             "w_up": ("experts", "embed", "mlp"),
+             "w_down": ("experts", "mlp", "embed")}
+        if self.cfg.n_shared:
+            a.update(shared_gate=("embed", "mlp"), shared_up=("embed", "mlp"),
+                     shared_down=("mlp", "embed"))
+        return a
+
+    def routed(self, p: Params, x2d: jax.Array):
+        """The held experts' part of the layer for tokens x2d (T, D):
+        (y (T, D), pairs routed to held experts, pairs routed elsewhere)."""
+        c = self.cfg
+        t, d = x2d.shape
+        logits = jnp.einsum("td,de->te", x2d.astype(jnp.float32),
+                            p["router"].astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        w, e = route(logits, c)
+        # (T, held) routing weights: a pair routed elsewhere, or not
+        # routed, weighs 0.
+        local = e - c.held[0]
+        held = (local >= 0) & (local < c.n_held)
+        weight = (jax.nn.one_hot(jnp.where(held, local, c.n_held), c.n_held,
+                                 dtype=jnp.float32) * w[..., None]).sum(1)
+        # Every held expert runs over every token, so no pair can be
+        # dropped.  At decode sizes (T up to a few hundred) this is no
+        # more matmul work than a grouped kernel's tile per expert, and
+        # XLA reads each layer's expert weights in place inside the
+        # layer scan.
+        wt = (lambda name: p[name].astype(x2d.dtype))
+        h = jax.nn.silu(jnp.einsum("td,edf->etf", x2d, wt("w_gate"))) \
+            * jnp.einsum("td,edf->etf", x2d, wt("w_up"))
+        ys = jnp.einsum("etf,efd->etd", h, wt("w_down"))
+        y = jnp.einsum("etd,te->td", ys, weight,
+                       preferred_element_type=jnp.float32).astype(x2d.dtype)
+        n_held = held.sum(dtype=jnp.int32)
+        return y, n_held, jnp.int32(t * c.top_k) - n_held
+
+    def shared(self, p: Params, x: jax.Array) -> jax.Array:
+        """The shared experts, on every token."""
+        wt = (lambda name: p[name].astype(x.dtype))
+        h = jax.nn.silu(x @ wt("shared_gate")) * (x @ wt("shared_up"))
+        return h @ wt("shared_down")
+
+    def apply(self, p: Params, x: jax.Array):
+        """x: (B, S, D) -> (y, {"routed_held": n, "routed_elsewhere": m})."""
+        b, s, d = x.shape
+        y, n_held, n_else = self.routed(p, x.reshape(b * s, d))
+        y = y.reshape(b, s, d)
+        if self.cfg.n_shared:
+            y = y + self.shared(p, x)
+        return y, {"routed_held": n_held, "routed_elsewhere": n_else}

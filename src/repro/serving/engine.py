@@ -255,6 +255,13 @@ class Engine:
         # construction, not at the first overloaded tick.
         self._admission_fn = get_admission(self.admission_strategy)
         self._step = jax.jit(self._decode_one)
+        # The step's counters (a model whose layers count, such as the
+        # held-expert layer's routed pairs; an empty dict for every other
+        # model, whose step then takes none): summed on the device by the
+        # step, read by step_counters().
+        init = getattr(self.model, "init_counts", dict)
+        self._counts = init()
+        self._counted = {k: 0 for k in self._counts}
         stacked = isinstance(self.cache_mesh, StackedTopology)
         self.fabric = None
         if self.track_transfers:
@@ -312,7 +319,14 @@ class Engine:
         self.n_weight_preparations = 0
         self.n_weight_reuses = 0
 
-    def _decode_one(self, params, token, caches, pos, memory=None):
+    def _decode_one(self, params, token, caches, pos, memory=None,
+                    counts=None):
+        """One model step: (logits, caches).  Given ``counts`` (a model
+        whose layers count), (logits, caches, ``counts`` with the step's
+        counters added)."""
+        if counts is not None:
+            return self.model.decode_step_counted(params, token, caches,
+                                                  pos, counts)
         if isinstance(self.model, EncDecLM):
             logits, caches = self.model.decode_step(params, token, caches,
                                                     pos, memory)
@@ -320,6 +334,31 @@ class Engine:
             logits, caches = self.model.decode_step(params, token, caches,
                                                     pos)
         return logits, caches
+
+    def _run_step(self, params, token, caches, pos, memory):
+        """``self._step``; a model's counters, where it has any, go in and
+        come back summed, and stay on the device."""
+        if not self._counts:
+            return self._step(params, token, caches, pos, memory)
+        logits, caches, self._counts = self._step(params, token, caches, pos,
+                                                  memory, self._counts)
+        return logits, caches
+
+    def step_counters(self) -> dict:
+        """Totals of the model step's counters over the engine's lifetime
+        (an empty dict for a model that counts nothing): for the
+        held-expert layer, ``routed_held`` and ``routed_elsewhere``, the
+        (token, expert) pairs routed to experts held here and to the
+        others, summed over layers and steps.  The device-side sums are
+        read (one transfer) and folded into host integers here, not per
+        step."""
+        if not self._counts:
+            return {}
+        got = jax.device_get(self._counts)
+        self._counts = jax.tree.map(jnp.zeros_like, self._counts)
+        for k, v in got.items():
+            self._counted[k] += int(v)
+        return dict(self._counted)
 
     def _serving_params(self, params, span):
         """``model.serving_params(params)``, built once per set of weight
@@ -896,7 +935,7 @@ class Engine:
                 # phases); the span ends once the first token is dispatched.
                 with span("engine.prefill", prompt_len=plen):
                     for i in range(plen):
-                        logits, caches = self._step(
+                        logits, caches = self._run_step(
                             params, prompt[:, i:i + 1], caches, jnp.int32(i),
                             memory)
                         if name is not None:
@@ -905,8 +944,8 @@ class Engine:
                                      axis=-1).astype(jnp.int32)
                 out = [prompt, tok]
                 for i in range(plen, plen + n_new - 1):
-                    logits, caches = self._step(params, tok, caches,
-                                                jnp.int32(i), memory)
+                    logits, caches = self._run_step(params, tok, caches,
+                                                    jnp.int32(i), memory)
                     tok = jnp.argmax(logits[:, -1:],
                                      axis=-1).astype(jnp.int32)
                     out.append(tok)

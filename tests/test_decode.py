@@ -77,8 +77,11 @@ def _greedy_loop(model, params, prompt, n_new):
 
 
 # qwen1.5: QKV biases, untied float32 head; gemma3: zero-centered norms
-# and QK norms (kept float32 in the serving copy), tied head, window ring.
-@pytest.mark.parametrize("arch", ["qwen1.5-4b", "gemma3-27b"])
+# and QK norms (kept float32 in the serving copy), tied head, window ring;
+# deepseek-v2: latent attention and its norms, a leading dense layer,
+# held experts with their float32 router, the step's counters.
+@pytest.mark.parametrize("arch", ["qwen1.5-4b", "gemma3-27b",
+                                  "deepseek-v2-236b-a21b"])
 def test_generate_engine(arch, mesh1):
     from repro.serving import Engine
     cfg = get_config(arch, smoke=True)

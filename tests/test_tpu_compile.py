@@ -142,3 +142,38 @@ def test_served_decode_step_fits_one_chip(one_chip):
     for shape in weights:
         out = re.compile(rf"= \w+\[{','.join(map(str, shape))}\]")
         assert not any(out.search(ln) for ln in converts), shape
+
+
+def test_served_deepseek_v2_step_fits_one_chip(one_chip):
+    """The decode step of ``serve.dsv2.chat128``: DeepSeek-V2 at published
+    widths, one dense and four expert layers holding experts 0-7 of 160,
+    an eighth of the vocabulary, 128 rows of 200 positions of latent
+    cache, on the Engine's serving copy with its counters.  With the
+    float32 leaves the copy casts it fits the chip, and the chip's
+    compiler converts no weight in it."""
+    cfg = dataclasses.replace(get_config("deepseek-v2-236b-a21b"),
+                              n_layers=5, experts_held=(0, 8), vocab=12_800)
+    model = make_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    raw = jax.eval_shape(model.init, key)
+    served = jax.eval_shape(model.serving_params, raw)
+    caches = on_chip(jax.eval_shape(lambda: model.init_caches(128, 200)))
+    text, total = _compile(model.decode_step_counted, on_chip(served),
+                           _spec(one_chip, (128, 1), jnp.int32), caches,
+                           _spec(one_chip, (), jnp.int32),
+                           on_chip(jax.eval_shape(model.init_counts)))
+    held = sum(a.size * a.dtype.itemsize for a, s in
+               zip(jax.tree.leaves(raw), jax.tree.leaves(served))
+               if a.dtype != s.dtype)
+    assert total + held < HBM_BYTES, (total, held)
+    weights = [raw["embed"]["embedding"].shape] + [
+        a.shape for a in jax.tree.leaves(raw["stack"]) if a.ndim > 2]
+    converts = [ln for ln in text.splitlines() if "convert" in ln]
+    for shape in weights:
+        out = re.compile(rf"= \w+\[{','.join(map(str, shape))}\]")
+        assert not any(out.search(ln) for ln in converts), shape
