@@ -10,9 +10,11 @@ zero bits surviving at the destination are feasible circuits.
 
 Implementation layout (mirrors the hardware split):
 
-* :func:`wavefront_search` — the PE-matrix accelerator, vectorized JAX
-  (``vmap``-able over a batch of requests; the Pallas TPU kernel in
-  ``repro.kernels.slot_alloc`` implements the same contract).
+* :func:`wavefront_search_batch` — the PE-matrix accelerator, vectorized
+  JAX over a batch of requests, its upstream reads static shifts along
+  the mesh axes (no gather); :func:`wavefront_search` is a batch of one,
+  and the Pallas TPU kernel in ``repro.kernels.slot_alloc`` implements
+  the same contract.
 * :class:`SlotTable` — the CCU's occupancy bookkeeping (host-side numpy):
   per (router, port, slot) reservation expiry in TDM-window units, with
   *incrementally maintained* packed busy masks (reservations set bits
@@ -42,82 +44,103 @@ from .bitvec import UINT, bit_is_free, full_mask, rotr, rotr_np
 from .topology import (Mesh3D, N_PORTS, PORT_LOCAL, StackedTopology,
                        port_for)
 
-_STRIDES = ("X", "XY")  # doc only
-
 
 # ---------------------------------------------------------------------------
-# The PE-matrix search (pure JAX; jit + vmap friendly)
+# The PE-matrix search (pure JAX, batch-native, gather-free)
 # ---------------------------------------------------------------------------
+def wavefront_search_batch(occ, srcs, dsts, init_vecs, *, mesh: Mesh3D,
+                           n_slots: int) -> jax.Array:
+    """Propagate busy-vectors from each ``srcs[b]`` to every node of the
+    shortest-path lattice toward ``dsts[b]``, for a batch of requests
+    sharing one occupancy state.
+
+    This is the paper's "explore all possible paths ... in parallel" taken
+    one step further: concurrent request *searches* also run in parallel
+    (the CCU still reserves sequentially, in FIFO order).
+
+    Args:
+      occ: (n_nodes, N_PORTS) uint32 — busy mask per output port.
+      srcs, dsts: (B,) int node ids (traced).
+      init_vecs: (B,) uint32 — initial busy vector at each source (0 for a
+        fresh search; non-zero when composing multi-phase NoM-Light routes).
+
+    Returns:
+      (B, n_nodes) uint32: converged busy vector per node, indexed by the
+      slot at which that node's *output* crossbar would be used.
+      Out-of-lattice nodes hold the all-busy mask.  ``vec[b, dsts[b]] |
+      occ[dsts[b], LOCAL]`` is the availability vector of arrival slots.
+
+    Node ids are ``(z*Y + y)*X + x``, so the upstream node of ``v`` along
+    dimension d is ``v - sign_d * stride_d`` with strides ``(1, X, X*Y)``:
+    every upstream read is one of two static rolls of the flat node axis,
+    picked per request by its sign.  A roll wraps around that axis, but
+    only into positions where the hop is invalid (the upstream node of a
+    valid node lies inside the box), which are masked all-busy.  Node
+    coordinates come from arithmetic, so the search lowers without a
+    gather.
+    """
+    n = mesh.n_nodes
+    X, XY = mesh.X, mesh.X * mesh.Y
+    strides = (1, X, XY)
+    fm = jnp.asarray(full_mask(n_slots), UINT)
+    srcs = jnp.asarray(srcs, jnp.int32)
+    dsts = jnp.asarray(dsts, jnp.int32)
+    init_vecs = jnp.asarray(init_vecs, UINT) & fm
+
+    def coords_of(v):                                   # (B,) -> (B, 3)
+        return jnp.stack([v % X, (v // X) % mesh.Y, v // XY], axis=1)
+
+    src_c = coords_of(srcs)
+    dst_c = coords_of(dsts)
+    sign = jnp.sign(dst_c - src_c)                      # (B, 3) in {-1,0,1}
+    lo = jnp.minimum(src_c, dst_c)[:, :, None]
+    hi = jnp.maximum(src_c, dst_c)[:, :, None]
+    node_c = jnp.asarray(mesh.coord_array.T, jnp.int32)[None]  # (1, 3, n)
+    in_box = jnp.all((node_c >= lo) & (node_c <= hi), axis=1)  # (B, n)
+
+    # A hop along d into v is valid iff v lies in the box, has moved >= 1
+    # step along d away from the source, and d is a travel dimension.
+    moved = node_c != src_c[:, :, None]                         # (B, 3, n)
+    valid = in_box[:, None] & moved & (sign[:, :, None] != 0)   # (B, 3, n)
+    plus = sign[:, :, None] > 0                                 # (B, 3, 1)
+
+    # Occupancy of the output port each hop leaves its upstream node by:
+    # port 2d (sign +1) of v - stride_d, or port 2d+1 (sign -1) of
+    # v + stride_d.  Loop-invariant, so read once per program.
+    occ_up = jnp.stack([
+        jnp.where(plus[:, d], jnp.roll(occ[:, 2 * d], strides[d])[None],
+                  jnp.roll(occ[:, 2 * d + 1], -strides[d])[None])
+        for d in range(3)], axis=1)                             # (B, 3, n)
+
+    node_ids = jnp.arange(n, dtype=jnp.int32)[None]
+    is_src = node_ids == srcs[:, None]                          # (B, n)
+    vec0 = jnp.where(is_src, init_vecs[:, None], fm)
+    live = in_box & ~is_src
+
+    def body(_, vec):
+        new = fm
+        for d in range(3):
+            up = jnp.where(plus[:, d], jnp.roll(vec, strides[d], axis=1),
+                           jnp.roll(vec, -strides[d], axis=1))
+            v = rotr(up | occ_up[:, d], n_slots)
+            new = new & jnp.where(valid[:, d], v, fm)
+        # Source keeps its injected vector; out-of-lattice nodes stay busy.
+        return jnp.where(live, new, vec0)
+
+    # The lattice is a DAG of depth <= max_dist, so max_dist sweeps converge.
+    return jax.lax.fori_loop(0, mesh.max_dist, body, vec0)
+
+
 @partial(jax.jit, static_argnames=("mesh", "n_slots"))
 def wavefront_search(occ: jax.Array, src: jax.Array, dst: jax.Array,
                      init_vec: jax.Array, *, mesh: Mesh3D,
                      n_slots: int) -> jax.Array:
-    """Propagate busy-vectors from ``src`` to every node of the shortest-path
-    lattice toward ``dst``.
-
-    Args:
-      occ: (n_nodes, N_PORTS) uint32 — busy mask per output port.
-      src, dst: scalar int32 node ids (traced; may come from a vmapped batch).
-      init_vec: uint32 scalar — initial busy vector at the source (0 for a
-        fresh search; non-zero when composing multi-phase NoM-Light routes).
-
-    Returns:
-      (n_nodes,) uint32: converged busy vector per node, indexed by the slot
-      at which that node's *output* crossbar would be used.  Out-of-lattice
-      nodes hold the all-busy mask.  ``vec[dst] | occ[dst, LOCAL]`` is the
-      availability vector of arrival slots.
-    """
-    n = mesh.n_nodes
-    fm = jnp.asarray(full_mask(n_slots), UINT)
-    coords = jnp.asarray(mesh.coord_array)          # (n, 3)
-    src_c = coords[src]                             # (3,)
-    dst_c = coords[dst]
-    sign = jnp.sign(dst_c - src_c)                  # (3,) in {-1,0,1}
-    lo = jnp.minimum(src_c, dst_c)
-    hi = jnp.maximum(src_c, dst_c)
-    in_box = jnp.all((coords >= lo) & (coords <= hi), axis=1)  # (n,)
-
-    strides = jnp.asarray([1, mesh.X, mesh.X * mesh.Y], jnp.int32)
-    node_ids = jnp.arange(n, dtype=jnp.int32)
-
-    # Per-dimension upstream node id and validity.
-    # upstream_d(v) = v - sign_d * stride_d ; valid iff we have moved >=1 step
-    # in dimension d away from the source and d is a travel dimension.
-    ups = node_ids[None, :] - sign[:, None] * strides[:, None]      # (3, n)
-    moved = coords.T != src_c[:, None]                              # (3, n)
-    valid = in_box[None, :] & moved & (sign[:, None] != 0)          # (3, n)
-    ups = jnp.clip(ups, 0, n - 1)
-
-    # Output port used at the upstream node for a hop along dim d, dir sign_d.
-    ports = jnp.where(sign < 0, 2 * jnp.arange(3) + 1, 2 * jnp.arange(3))
-
-    vec0 = jnp.full((n,), fm, UINT).at[src].set(jnp.asarray(init_vec, UINT))
-    is_src = node_ids == src
-
-    def body(_, vec):
-        def cand(d):
-            up = ups[d]
-            v = vec[up] | occ[up, ports[d]]
-            v = rotr(v, n_slots)
-            return jnp.where(valid[d], v, fm)
-        new = cand(0) & cand(1) & cand(2)
-        # Source keeps its injected vector; out-of-lattice nodes stay busy.
-        return jnp.where(in_box & ~is_src, new, vec0)
-
-    # The lattice is a DAG of depth <= max_dist, so max_dist sweeps converge.
-    vec = jax.lax.fori_loop(0, mesh.max_dist, body, vec0)
-    return vec
-
-
-def wavefront_search_batch(occ, srcs, dsts, init_vecs, *, mesh, n_slots):
-    """vmap over a batch of (src, dst) requests sharing one occupancy state.
-
-    This is the paper's "explore all possible paths ... in parallel" taken one
-    step further: concurrent request *searches* also run in parallel (the CCU
-    still reserves sequentially, in FIFO order).
-    """
-    fn = partial(wavefront_search, mesh=mesh, n_slots=n_slots)
-    return jax.vmap(lambda s, d, iv: fn(occ, s, d, iv))(srcs, dsts, init_vecs)
+    """One request's search: :func:`wavefront_search_batch` on a batch of
+    one.  ``src``, ``dst`` are scalar node ids and ``init_vec`` a uint32
+    scalar; returns the (n_nodes,) uint32 converged busy vectors."""
+    return wavefront_search_batch(
+        occ, jnp.reshape(src, (1,)), jnp.reshape(dst, (1,)),
+        jnp.reshape(init_vec, (1,)), mesh=mesh, n_slots=n_slots)[0]
 
 
 @partial(jax.jit, static_argnames=("mesh", "n_slots"))

@@ -7,8 +7,9 @@ vectors.  This module fuses all three stages into a single jit program —
 the paper's claim that circuit setup happens at line rate inside the
 memory controller, restated as "one program dispatch per wave":
 
-* **wavefront fixpoint** — the same packed-uint32 formulation as
-  ``repro.core.slot_alloc.wavefront_search`` (vmapped), or the Pallas
+* **wavefront fixpoint** — ``repro.core.slot_alloc.wavefront_search_batch``
+  (packed uint32, the whole wave at once, its upstream reads static
+  shifts of the node axis rather than gathers), or the Pallas
   bit-plane kernel (``kernel="pallas"``) for allocators built with
   ``use_pallas=True``;
 * **slot scoring** — the int32 twin of ``_best_slots_np`` over the
@@ -219,7 +220,7 @@ def fused_prepare_program(occ, srcs, dsts, t_readys, *, mesh: Mesh3D,
       occ: (n, N_PORTS) uint32 device occupancy (``device_busy_masks``,
         version-keyed and reused across waves).
       srcs, dsts, t_readys: (B,) int32 per-call request buffers.
-      kernel: "jnp" (packed-uint32 vmapped wavefront + jnp scoring) or
+      kernel: "jnp" (packed-uint32 batch wavefront + jnp scoring) or
         "pallas" (bit-plane wavefront kernel + lane scoring kernel).
       interpret: Pallas interpret flag, only meaningful for "pallas".
 

@@ -1,19 +1,20 @@
 """Pure-host oracles for the slot-allocator kernels.
 
-``wavefront_search_ref_batch`` is the packed-uint32 jnp search from the
-core library (the paper-faithful implementation), evaluated one request
-at a time.  ``fused_prepare_ref`` and ``slot_score_ref`` are the numpy
-twins of the fused prepare program (``fused.fused_prepare``) — the
-differential harness (``tests/test_fused_alloc.py``) holds the compiled
-program bit-identical to these.
+``wavefront_search_ref_batch`` is the core library's scalar host twin of
+the search (``_wavefront_host``: one topological pass over each
+request's shortest-path box), independent of the jnp and Pallas
+formulations it checks.  ``fused_prepare_ref`` and ``slot_score_ref``
+are the numpy twins of the fused prepare program
+(``fused.fused_prepare``) — the differential harness
+(``tests/test_fused_alloc.py``) holds the compiled program bit-identical
+to these.
 """
 from __future__ import annotations
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.slot_alloc import (_best_slots_np, _wavefront_host,
-                                   traceback_batch, wavefront_search)
+                                   traceback_batch)
 from repro.core.topology import PORT_LOCAL, Mesh3D
 
 from .fused import FAR32, FusedPrepare
@@ -21,13 +22,11 @@ from .fused import FAR32, FusedPrepare
 
 def wavefront_search_ref_batch(occ_packed, srcs, dsts, init_vecs, *,
                                mesh: Mesh3D, n_slots: int):
-    outs = []
-    for s, d, iv in zip(np.asarray(srcs), np.asarray(dsts),
-                        np.asarray(init_vecs)):
-        outs.append(np.asarray(wavefront_search(
-            jnp.asarray(occ_packed), jnp.int32(int(s)), jnp.int32(int(d)),
-            jnp.uint32(int(iv)), mesh=mesh, n_slots=n_slots)))
-    return np.stack(outs)
+    occ = np.asarray(occ_packed, np.uint32)
+    return np.stack([_wavefront_host(occ, mesh, n_slots, int(s), int(d),
+                                     int(iv))
+                     for s, d, iv in zip(np.asarray(srcs), np.asarray(dsts),
+                                         np.asarray(init_vecs))])
 
 
 def slot_score_ref(avail: np.ndarray, dists: np.ndarray,
