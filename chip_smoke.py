@@ -8,9 +8,9 @@
 Every phase runs in this one process, through the entry points a user
 calls, and raises on a failed check, so any failure exits non-zero.  A
 host whose first JAX device is not a TPU exits non-zero before any phase
-and names the platform it found: nothing falls back to the CPU, and no
-Pallas kernel runs in interpret mode.  Progress goes to earlier lines; the
-last line of stdout is one JSON object naming the device.
+and names the platform it found: nothing falls back to the CPU.  Progress
+goes to earlier lines; the last line of stdout is one JSON object naming
+the device.
 """
 from __future__ import annotations
 
@@ -29,7 +29,7 @@ import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
-from repro.core import (NomFabric, TdmAllocator, TransferRequest,  # noqa: E402
+from repro.core import (NomFabric, TransferRequest,  # noqa: E402
                         make_topology, nom_all_to_all, nom_allreduce)
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_mesh, set_ambient_mesh  # noqa: E402
@@ -113,18 +113,16 @@ def circuit_bytes(results) -> bytes:
 
 def fabric_phase() -> dict:
     """Schedule one batch on the default (``auto``) fabric, then the same
-    batch on a host-pipeline fabric and on a Pallas-route allocator; all
-    three must grant byte-identical circuits, and the default fabric must
-    have run the compiled prepare program at least once."""
+    batch on a host-pipeline fabric; both must grant byte-identical
+    circuits, and the default fabric must have run the compiled prepare
+    program at least once."""
     mesh = make_topology()
     reqs = fabric_requests(mesh, FABRIC_REQUESTS)
     runs = {}
     for name, fabric in (
             ("auto", NomFabric(mesh=mesh, n_slots=N_SLOTS)),
             ("host", NomFabric(mesh=mesh, n_slots=N_SLOTS,
-                               alloc_backend="host")),
-            ("pallas", NomFabric(allocator=TdmAllocator(
-                mesh, N_SLOTS, use_pallas=True)))):
+                               alloc_backend="host"))):
         results, _ = fabric.schedule(reqs)
         tel = fabric.telemetry()
         runs[name] = (circuit_bytes(results), tel)
@@ -139,11 +137,10 @@ def fabric_phase() -> dict:
                              "(fused_waves=0)")
     if tel["scheduled"] < 1:
         raise AssertionError("the fabric granted no circuit")
-    for name in ("auto", "pallas"):
-        if runs[name][0] != runs["host"][0]:
-            raise AssertionError(f"fabric[{name}] circuits differ from the "
-                                 "host pipeline's")
-    log(f"fabric: auto and pallas circuits byte-identical to host "
+    if runs["auto"][0] != runs["host"][0]:
+        raise AssertionError("fabric[auto] circuits differ from the host "
+                             "pipeline's")
+    log(f"fabric: auto circuits byte-identical to host "
         f"({len(runs['host'][0])} bytes)")
     return tel
 

@@ -12,9 +12,7 @@ Implementation layout (mirrors the hardware split):
 
 * :func:`wavefront_search_batch` — the PE-matrix accelerator, vectorized
   JAX over a batch of requests, its upstream reads static shifts along
-  the mesh axes (no gather); :func:`wavefront_search` is a batch of one,
-  and the Pallas TPU kernel in ``repro.kernels.slot_alloc`` implements
-  the same contract.
+  the mesh axes (no gather); :func:`wavefront_search` is a batch of one.
 * :class:`SlotTable` — the CCU's occupancy bookkeeping (host-side numpy):
   per (router, port, slot) reservation expiry in TDM-window units, with
   *incrementally maintained* packed busy masks (reservations set bits
@@ -773,8 +771,7 @@ class TdmAllocator:
     """
 
     def __init__(self, mesh: Mesh3D, n_slots: int = 16,
-                 link_bytes: int = 8, use_pallas: bool = False,
-                 backend: str = "auto"):
+                 link_bytes: int = 8, backend: str = "auto"):
         if backend not in ("auto", "host", "fused"):
             raise ValueError(f"backend must be auto|host|fused, "
                              f"got {backend!r}")
@@ -790,18 +787,6 @@ class TdmAllocator:
         # re-search) where dispatch overhead dwarfs the compute.
         self.backend = backend
         self._last_prepare_backend = "host"
-        # use_pallas routes every search through the kernel (no host
-        # small-batch shortcut), so kernel tests exercise it end to end;
-        # the fused program then runs its Pallas wavefront/scoring route.
-        self._host_small = not use_pallas
-        self._fused_kernel = "pallas" if use_pallas else "jnp"
-        if use_pallas:  # pragma: no cover - exercised in kernel tests
-            from repro.kernels.slot_alloc import ops as _ops
-            self._search_batch = partial(_ops.wavefront_search_pallas_batch,
-                                         mesh=mesh, n_slots=n_slots)
-        else:
-            self._search_batch = partial(_search_batch_jit, mesh=mesh,
-                                         n_slots=n_slots)
 
     # An in-place INIT clears one DRAM row per TDM window (RowClone-FPM in
     # the bank; no bytes cross the mesh), so its zero-hop circuit holds the
@@ -902,10 +887,6 @@ class TdmAllocator:
         # fast path, not the whole tail.  (A state re-searched after a
         # conflict commits immediately, so the bitmaps never need
         # per-state sequencing.)
-        # Deferred circuit emission of the last fused wave: its
-        # reservations are final but its Circuit objects are built
-        # overlapped with the *next* wave's device program.
-        pending = None
         for lo in range(0, len(reqs), self.search_wave):
             hi = min(lo + self.search_wave, len(reqs))
             wave = reqs[lo:hi]
@@ -918,22 +899,17 @@ class TdmAllocator:
                 # entirely — the struct-of-arrays commit below.
                 token = self._dispatch_wave_fused(wave, t_readys[lo:hi],
                                                   window)
-                if pending is not None:
-                    self._emit_wave_fused(pending, results, cycle)
                 report.search_rounds += 1
                 report.n_searched += len(wave)
                 report.fused_waves += 1
-                pending = self._commit_wave_fused(
+                rows, fp, n_win = self._commit_wave_fused(
                     token, wave, t_readys[lo:hi], lo, window, cycle,
                     results, report)
+                self._emit_wave_fused(wave, lo, rows, fp, n_win, results,
+                                      cycle)
                 continue
-            if pending is not None:
-                self._emit_wave_fused(pending, results, cycle)
-                pending = None
             self._commit_wave_states(wave, t_readys[lo:hi], lo, window,
                                      cycle, results, report)
-        if pending is not None:
-            self._emit_wave_fused(pending, results, cycle)
         self.last_report = report
         return results
 
@@ -1119,16 +1095,14 @@ class TdmAllocator:
     def _dispatch_wave_fused(self, wave: list[CopyRequest],
                              t_w: np.ndarray, window: int):
         """Upload the occupancy and launch the fused program for a wave
-        without blocking (JAX async dispatch) — the fast path emits the
-        previous wave's circuits while the device searches this one."""
+        without blocking (JAX async dispatch)."""
         from repro.kernels.slot_alloc import fused as _fused
         B = len(wave)
         srcs = np.fromiter((r.src for r in wave), np.int64, B)
         dsts = np.fromiter((r.dst for r in wave), np.int64, B)
         return _fused.fused_prepare_start(
             self.table.device_busy_masks(window), srcs, dsts, t_w,
-            mesh=self.mesh, n_slots=self.n_slots,
-            kernel=self._fused_kernel)
+            mesh=self.mesh, n_slots=self.n_slots)
 
     @staticmethod
     @partial(jax.profiler.annotate_function, name="nom.alloc.fused.wait")
@@ -1139,12 +1113,13 @@ class TdmAllocator:
         return _fused.fused_prepare_wait(token)
 
     @partial(jax.profiler.annotate_function, name="nom.alloc.fused.emit")
-    def _emit_wave_fused(self, pending, results, cycle: int) -> None:
-        """Deferred circuit emission for a fused wave's clean commits:
-        pure bookkeeping (no table access), so it runs overlapped with
-        the next wave's device program."""
-        wave, lo, rows, fp, n_win, dists_l = pending
+    def _emit_wave_fused(self, wave: list[CopyRequest], lo: int, rows,
+                         fp, n_win: np.ndarray, results, cycle: int) -> None:
+        """Build the Circuit results of a fused wave's clean commits
+        (``rows``, whose reservations :meth:`_commit_wave_fused` made):
+        pure bookkeeping, no table access."""
         n = self.n_slots
+        dists_l = fp.dists.tolist()
         starts_l = fp.starts.tolist()
         nwin_l = n_win.tolist()
         hn_l = fp.hop_n.tolist()
@@ -1183,9 +1158,9 @@ class TdmAllocator:
         then sees the loser's fresh claims.  Bit-identical to streaming
         the wave through :meth:`allocate`.
 
-        Returns the deferred emission record for
-        :meth:`_emit_wave_fused` — reservations and conflict results are
-        final when this returns, but clean commits' Circuit objects are
+        Returns ``(rows, fp, n_win)`` for :meth:`_emit_wave_fused`:
+        reservations and the results of denials and conflicts are final
+        when this returns, but the clean commits' Circuit objects are
         not yet built."""
         n = self.n_slots
         B = len(wave)
@@ -1275,7 +1250,7 @@ class TdmAllocator:
             results[lo + j] = AllocResult(out, cycle)
             if j + 1 < b:
                 segs[p:p] = [(j + 1, b)]
-        return wave, lo, emit_rows, fp, n_win, dists_l
+        return emit_rows, fp, n_win
 
     # -- search + vectorized post-search pipeline -----------------------------
     def _run_search(self, occ, window, srcs, dsts, inits) -> np.ndarray:
@@ -1289,7 +1264,7 @@ class TdmAllocator:
         host topological evaluation is cheaper than the dispatch.
         Returns (len(srcs), n_nodes) uint32 busy vectors (numpy)."""
         m = len(srcs)
-        if self._host_small and m <= _SMALL_SEARCH:
+        if m <= _SMALL_SEARCH:
             return np.stack([
                 _wavefront_host(occ, self.mesh, self.n_slots, int(s),
                                 int(d), int(iv))
@@ -1300,7 +1275,8 @@ class TdmAllocator:
         d = np.zeros(pad, np.int32)
         iv = np.zeros(pad, np.uint32)
         s[:m], d[:m], iv[:m] = srcs, dsts, inits
-        vecs = self._search_batch(occ_dev, s, d, iv)
+        vecs = _search_batch_jit(occ_dev, s, d, iv, mesh=self.mesh,
+                                 n_slots=self.n_slots)
         return np.asarray(vecs)[:m]
 
     def _prepare_states(self, reqs: list[CopyRequest], t_readys: np.ndarray,
@@ -1419,7 +1395,7 @@ class TdmAllocator:
         if req.op == "reduce":
             occ = self.table._ports.masks_at(window)
             return self._prepare_reduce(req, int(t_ready[0]), occ, window)
-        if self._host_small and self.backend != "fused":
+        if self.backend != "fused":
             occ = self.table._ports.masks_at(window)
             vec = _wavefront_host(occ, self.mesh, self.n_slots, req.src,
                                   req.dst, 0)

@@ -1,11 +1,12 @@
 """Backend-aware default for the Pallas ``interpret`` flag.
 
-Every kernel in this package takes ``interpret: bool | None = None`` and
-resolves ``None`` through :func:`default_interpret`: compiled Pallas
-(``interpret=False``) on accelerator backends, interpreter mode on CPU,
-where compiled Pallas calls are refused.  Passing an explicit
+Every Pallas kernel in this package takes ``interpret: bool | None =
+None`` and resolves ``None`` through :func:`default_interpret`: compiled
+Pallas (``interpret=False``) on accelerator backends, interpreter mode
+on CPU, where compiled Pallas calls are refused.  Passing an explicit
 ``True``/``False`` always wins (the compiled/interpret parity tests pass
-``False`` on purpose and record the skip reason when the backend refuses).
+``False`` on purpose and record the skip reason when the backend
+refuses).
 """
 from __future__ import annotations
 

@@ -1,8 +1,6 @@
-from . import fused, ops, ref
+from . import fused, ref
 from .fused import (fused_prepare, fused_prepare_program, fused_prepare_start,
-                    fused_prepare_wait, slot_score_planes)
-from .slot_alloc import wavefront_search_planes
+                    fused_prepare_wait)
 
-__all__ = ["fused", "ops", "ref", "wavefront_search_planes",
-           "fused_prepare", "fused_prepare_program", "fused_prepare_start",
-           "fused_prepare_wait", "slot_score_planes"]
+__all__ = ["fused", "ref", "fused_prepare", "fused_prepare_program",
+           "fused_prepare_start", "fused_prepare_wait"]

@@ -9,12 +9,9 @@ memory controller, restated as "one program dispatch per wave":
 
 * **wavefront fixpoint** — ``repro.core.slot_alloc.wavefront_search_batch``
   (packed uint32, the whole wave at once, its upstream reads static
-  shifts of the node axis rather than gathers), or the Pallas
-  bit-plane kernel (``kernel="pallas"``) for allocators built with
-  ``use_pallas=True``;
+  shifts of the node axis rather than gathers);
 * **slot scoring** — the int32 twin of ``_best_slots_np`` over the
-  availability vectors at each destination (Pallas lane kernel in
-  ``kernel="pallas"`` mode, plain jnp otherwise);
+  availability vectors at each destination;
 * **trace-back** — a ``lax.scan`` lockstep walk (one step per hop, whole
   batch at once) whose per-step outputs are assembled into forward hop
   arrays by one vectorized gather, still inside the program.
@@ -43,17 +40,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
 
-from repro.core.bitvec import UINT, full_mask
+from repro.core.bitvec import UINT
 from repro.core.topology import PORT_LOCAL, Mesh3D
-from repro.kernels.interpret import resolve_interpret
-
-from .ops import pack_bits, unpack_bits
-from .slot_alloc import LANES, wavefront_search_planes
 
 __all__ = ["FusedPrepare", "fused_prepare", "fused_prepare_program",
-           "slot_score_planes", "FAR32"]
+           "FAR32"]
 
 # int32 "infeasible" sentinel — the host twin (`_best_slots_np`) uses
 # int64 2**62; any feasible start cycle is strictly below either, so the
@@ -65,86 +57,19 @@ FAR32 = np.int32(2 ** 31 - 1)
 # ---------------------------------------------------------------------------
 # Slot scoring
 # ---------------------------------------------------------------------------
-def _score_kernel(avail_ref, dists_ref, tready_ref, cost_ref, *,
-                  n_slots: int):
-    """Pallas lane kernel: per-(request, arrival-slot) start-cycle cost.
-
-    avail: (B, LANES) int32 0/1 busy planes of ``vec[dst] | occ[dst,
-    LOCAL]``; cost[b, s] = earliest injection cycle >= t_ready that
-    arrives at slot s (FAR32 when s is busy or beyond n_slots).
-    """
-    avail = avail_ref[...]
-    dists = dists_ref[...]                 # (B, 1)
-    t = tready_ref[...]                    # (B, 1)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, avail.shape, 1)
-    s_inj = (lanes - dists) % n_slots
-    c = t + ((s_inj - t) % n_slots)
-    free = (avail == 0) & (lanes < n_slots)
-    cost_ref[...] = jnp.where(free, c, jnp.int32(FAR32))
-
-
-@partial(jax.jit, static_argnames=("n_slots", "interpret"))
-def slot_score_planes(avail_planes: jax.Array, dists: jax.Array,
-                      t_readys: jax.Array, *, n_slots: int,
-                      interpret: bool | None = None) -> jax.Array:
-    """Pallas slot scoring over availability bit-planes.
-
-    avail_planes: (B, LANES) int32 0/1 (busy); dists, t_readys: (B,)
-    int32.  Returns the (B, LANES) int32 cost matrix; argmin over it is
-    the chosen arrival slot (ties resolve to the lowest slot, same as
-    the serial ascending scan).  Oracle: ``ref.slot_score_ref``.
-    """
-    B = avail_planes.shape[0]
-    kernel = partial(_score_kernel, n_slots=n_slots)
-    return pl.pallas_call(
-        kernel,
-        in_specs=[pl.BlockSpec((B, LANES), lambda: (0, 0)),
-                  pl.BlockSpec((B, 1), lambda: (0, 0)),
-                  pl.BlockSpec((B, 1), lambda: (0, 0))],
-        out_specs=pl.BlockSpec((B, LANES), lambda: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, LANES), jnp.int32),
-        interpret=resolve_interpret(interpret),
-    )(avail_planes, dists[:, None], t_readys[:, None])
-
-
 def _score_jnp(avail: jax.Array, dists: jax.Array, t_readys: jax.Array,
                n_slots: int) -> jax.Array:
-    """jnp twin of :func:`slot_score_planes` on packed uint32 vectors:
-    (B, n_slots) int32 cost matrix."""
+    """Per-(request, arrival-slot) start-cycle cost on packed uint32
+    availability vectors: the (B, n_slots) int32 cost matrix, cost[b, s]
+    the earliest injection cycle >= t_ready that arrives at slot s
+    (FAR32 when s is busy).  argmin over it is the chosen arrival slot
+    (ties resolve to the lowest slot, as the serial ascending scan does).
+    Oracle: ``ref.slot_score_ref``."""
     slots = jnp.arange(n_slots, dtype=jnp.int32)
     free = ((avail[:, None] >> slots[None].astype(UINT)) & 1) == 0
     s_inj = (slots[None] - dists[:, None]) % n_slots
     c = t_readys[:, None] + ((s_inj - t_readys[:, None]) % n_slots)
     return jnp.where(free, c, jnp.int32(FAR32))
-
-
-# ---------------------------------------------------------------------------
-# Wavefront (Pallas bit-plane route, traced geometry)
-# ---------------------------------------------------------------------------
-def _wavefront_planes(occ, srcs, dsts, init_vecs, *, mesh: Mesh3D,
-                      n_slots: int, interpret: bool | None) -> jax.Array:
-    """The Pallas plane kernel with trace-safe (jnp) geometry, so it can
-    live inside the fused program; contract of ``wavefront_search_batch``."""
-    coords = jnp.asarray(mesh.coord_array)
-    sc = coords[srcs]
-    dc = coords[dsts]
-    sign = jnp.sign(dc - sc).astype(jnp.int32)
-    lo = jnp.minimum(sc, dc)[:, None, :]
-    hi = jnp.maximum(sc, dc)[:, None, :]
-    in_box = ((coords[None] >= lo) & (coords[None] <= hi)).all(-1)
-    moved = coords[None, :, :] != sc[:, None, :]
-    valid = (in_box[:, :, None] & moved & (sign[:, None, :] != 0)) \
-        .transpose(0, 2, 1).astype(jnp.int32)
-    occ_planes = unpack_bits(occ.T[:6], n_slots)
-    B = srcs.shape[0]
-    fm = jnp.asarray(full_mask(n_slots), UINT)
-    init_packed = jnp.full((B, mesh.n_nodes), fm, UINT) \
-        .at[jnp.arange(B), srcs].set(init_vecs.astype(UINT))
-    out = wavefront_search_planes(
-        sign, valid, unpack_bits(init_packed, n_slots), occ_planes,
-        mesh_shape=(mesh.X, mesh.Y, mesh.Z), n_slots=n_slots,
-        interpret=interpret)
-    return pack_bits(out, n_slots)
 
 
 # ---------------------------------------------------------------------------
@@ -210,19 +135,15 @@ def _traceback_scan(vecs, occ, jreq, jsrc, jdst, a0, *, mesh: Mesh3D,
 # ---------------------------------------------------------------------------
 # The fused program
 # ---------------------------------------------------------------------------
-@partial(jax.jit, static_argnames=("mesh", "n_slots", "kernel", "interpret"))
+@partial(jax.jit, static_argnames=("mesh", "n_slots"))
 def fused_prepare_program(occ, srcs, dsts, t_readys, *, mesh: Mesh3D,
-                          n_slots: int, kernel: str = "jnp",
-                          interpret: bool | None = None):
+                          n_slots: int):
     """One compiled program: wavefront + slot scoring + trace-back.
 
     Args:
       occ: (n, N_PORTS) uint32 device occupancy (``device_busy_masks``,
         version-keyed and reused across waves).
       srcs, dsts, t_readys: (B,) int32 per-call request buffers.
-      kernel: "jnp" (packed-uint32 batch wavefront + jnp scoring) or
-        "pallas" (bit-plane wavefront kernel + lane scoring kernel).
-      interpret: Pallas interpret flag, only meaningful for "pallas".
 
     Returns ``(ints, flags, vecs)``: ``ints`` is (B, 3 + 3*(max_dist+1))
     int32 — columns [starts, arr, dists, hop_n..., hop_p..., hop_s...];
@@ -231,29 +152,19 @@ def fused_prepare_program(occ, srcs, dsts, t_readys, *, mesh: Mesh3D,
     trace-backs.  :func:`fused_prepare` unpacks them into a
     :class:`FusedPrepare`.
     """
-    # Local import: core.slot_alloc lazily imports this package for
-    # use_pallas allocators, so the top level must stay one-directional.
+    # Local import: core.slot_alloc lazily imports this module to run
+    # its fused waves, so the top level must stay one-directional.
     from repro.core.slot_alloc import wavefront_search_batch
 
     B = srcs.shape[0]
     rows = jnp.arange(B)
     init = jnp.zeros(B, UINT)
-    if kernel == "pallas":
-        vecs = _wavefront_planes(occ, srcs, dsts, init, mesh=mesh,
-                                 n_slots=n_slots, interpret=interpret)
-    else:
-        vecs = wavefront_search_batch(occ, srcs, dsts, init, mesh=mesh,
-                                      n_slots=n_slots)
+    vecs = wavefront_search_batch(occ, srcs, dsts, init, mesh=mesh,
+                                  n_slots=n_slots)
     coords = jnp.asarray(mesh.coord_array)
     dists = jnp.abs(coords[dsts] - coords[srcs]).sum(1)
     avail = vecs[rows, dsts] | occ[dsts, PORT_LOCAL]
-    if kernel == "pallas":
-        cost = slot_score_planes(
-            unpack_bits(avail, n_slots), dists.astype(jnp.int32),
-            t_readys.astype(jnp.int32), n_slots=n_slots,
-            interpret=interpret)[:, :n_slots]
-    else:
-        cost = _score_jnp(avail, dists, t_readys, n_slots)
+    cost = _score_jnp(avail, dists, t_readys, n_slots)
     arr = jnp.argmin(cost, 1).astype(jnp.int32)
     starts = cost[rows, arr]
     free = cost != jnp.int32(FAR32)
@@ -299,14 +210,12 @@ def _pow2_pad(n: int) -> int:
 
 
 def fused_prepare_start(occ, srcs, dsts, t_readys, *, mesh: Mesh3D,
-                        n_slots: int, kernel: str = "jnp",
-                        interpret: bool | None = None):
+                        n_slots: int):
     """Dispatch the fused program for one wave without blocking.
 
     JAX dispatch is asynchronous: the returned token holds in-flight
-    device arrays, so the host can overlap bookkeeping (the previous
-    wave's circuit emission) with the device's search.  Pass the token
-    to :func:`fused_prepare_wait` to pull the outputs.
+    device arrays.  Pass it to :func:`fused_prepare_wait` to pull the
+    outputs.
     """
     B = len(srcs)
     pad = _pow2_pad(B)
@@ -317,8 +226,7 @@ def fused_prepare_start(occ, srcs, dsts, t_readys, *, mesh: Mesh3D,
     d[:B] = dsts
     t[:B] = t_readys
     outs = fused_prepare_program(
-        jnp.asarray(occ), s, d, t, mesh=mesh, n_slots=n_slots,
-        kernel=kernel, interpret=resolve_interpret(interpret))
+        jnp.asarray(occ), s, d, t, mesh=mesh, n_slots=n_slots)
     return outs, B, mesh
 
 
@@ -336,9 +244,8 @@ def fused_prepare_wait(token) -> FusedPrepare:
         dists=ints[:, 2], _vecs_dev=vecs, _batch=B)
 
 
-def fused_prepare(occ, srcs, dsts, t_readys, *, mesh: Mesh3D, n_slots: int,
-                  kernel: str = "jnp",
-                  interpret: bool | None = None) -> FusedPrepare:
+def fused_prepare(occ, srcs, dsts, t_readys, *, mesh: Mesh3D,
+                  n_slots: int) -> FusedPrepare:
     """Run the fused program for one wave and pull the host-side outputs.
 
     ``srcs``/``dsts``/``t_readys`` are host arrays of any int dtype (the
@@ -347,5 +254,4 @@ def fused_prepare(occ, srcs, dsts, t_readys, *, mesh: Mesh3D, n_slots: int,
     device array (``SlotTable.device_busy_masks``) or host uint32 masks.
     """
     return fused_prepare_wait(fused_prepare_start(
-        occ, srcs, dsts, t_readys, mesh=mesh, n_slots=n_slots,
-        kernel=kernel, interpret=interpret))
+        occ, srcs, dsts, t_readys, mesh=mesh, n_slots=n_slots))

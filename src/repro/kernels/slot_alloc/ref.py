@@ -1,13 +1,11 @@
-"""Pure-host oracles for the slot-allocator kernels.
+"""Pure-host oracles of the fused prepare program.
 
-``wavefront_search_ref_batch`` is the core library's scalar host twin of
-the search (``_wavefront_host``: one topological pass over each
-request's shortest-path box), independent of the jnp and Pallas
-formulations it checks.  ``fused_prepare_ref`` and ``slot_score_ref``
-are the numpy twins of the fused prepare program
-(``fused.fused_prepare``) — the differential harness
-(``tests/test_fused_alloc.py``) holds the compiled program bit-identical
-to these.
+``fused_prepare_ref`` and ``slot_score_ref`` are the numpy twins of
+``fused.fused_prepare`` and its slot scoring; their search is the core
+library's scalar host twin (``_wavefront_host``: one topological pass
+over each request's shortest-path box), independent of the batch search
+it checks.  The differential harness (``tests/test_fused_alloc.py``)
+holds the compiled program bit-identical to these.
 """
 from __future__ import annotations
 
@@ -20,18 +18,9 @@ from repro.core.topology import PORT_LOCAL, Mesh3D
 from .fused import FAR32, FusedPrepare
 
 
-def wavefront_search_ref_batch(occ_packed, srcs, dsts, init_vecs, *,
-                               mesh: Mesh3D, n_slots: int):
-    occ = np.asarray(occ_packed, np.uint32)
-    return np.stack([_wavefront_host(occ, mesh, n_slots, int(s), int(d),
-                                     int(iv))
-                     for s, d, iv in zip(np.asarray(srcs), np.asarray(dsts),
-                                         np.asarray(init_vecs))])
-
-
 def slot_score_ref(avail: np.ndarray, dists: np.ndarray,
                    t_readys: np.ndarray, n_slots: int) -> np.ndarray:
-    """numpy twin of ``fused.slot_score_planes`` on packed uint32
+    """numpy twin of the fused program's slot scoring on packed uint32
     availability vectors: the (B, n_slots) int32 cost matrix."""
     slots = np.arange(n_slots, dtype=np.int64)
     free = ((avail.astype(np.int64)[:, None] >> slots[None]) & 1) == 0

@@ -1,6 +1,6 @@
 """``chip_smoke.py`` on the CPU: it refuses to run without a TPU, and its
-phases, run here at small sizes with Pallas in interpret mode (the
-four-chip phase on four virtual devices), pass."""
+phases, run here at small sizes (the four-chip phase on four virtual
+devices), pass."""
 import importlib.util
 import pathlib
 

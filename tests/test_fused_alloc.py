@@ -4,9 +4,10 @@ The allocator's fused backend runs wavefront search + slot choice +
 trace-back as ONE compiled program per wave and commits through a
 struct-of-arrays pipeline.  Correctness story, in three layers:
 
-* **kernel vs oracle** — ``fused_prepare`` (the compiled program) is
+* **program vs oracle** — ``fused_prepare`` (the compiled program) is
   bit-identical to ``ref.fused_prepare_ref`` (scalar host twin) on random
-  occupancies: starts, arrival slots, denials, hop arrays, distances;
+  occupancies, on meshes from one node to the paper's 8x8x4 at 16 and
+  32 slots: starts, arrival slots, denials, hop arrays, distances;
 * **pipeline vs serial** — ``allocate_batch`` under ``backend="fused"``
   is bit-identical to feeding the same stream through serial
   ``allocate`` one request at a time: circuits (every field), the final
@@ -34,6 +35,15 @@ MESHES = {
     "wide": Mesh3D(8, 8, 4),
 }
 N_SLOTS = {"tall": 8, "wide": 16}
+# The program-vs-oracle meshes: the two above, the paper's mesh at 32
+# slots (the widest busy vector), a one-column mesh and a single node.
+ORACLE_MESHES = {
+    "tall": (MESHES["tall"], N_SLOTS["tall"]),
+    "wide": (MESHES["wide"], N_SLOTS["wide"]),
+    "8x8x4/32": (Mesh3D(8, 8, 4), 32),
+    "1x4x2/8": (Mesh3D(1, 4, 2), 8),
+    "1x1x1/4": (Mesh3D(1, 1, 1, vault_span_y=1), 4),
+}
 
 
 def _stream(rng, mesh, n, *, contended=False, init_frac=0.0,
@@ -90,23 +100,25 @@ def _assert_stream_identical(mesh, n_slots, reqs, *, wave=None,
     return a_f
 
 
-# --- kernel vs host oracle ---------------------------------------------------
+# --- program vs host oracle --------------------------------------------------
+@pytest.mark.parametrize("mesh_pick", list(ORACLE_MESHES))
 @settings(max_examples=8, deadline=None)
-@given(st.integers(0, 2**31), st.integers(0, 1))
-def test_fused_prepare_matches_ref_oracle(seed, mesh_pick):
+@given(st.integers(0, 2**31), st.sampled_from([0.05, 0.3, 0.6, 1.0]))
+def test_fused_prepare_matches_ref_oracle(mesh_pick, seed, density):
     """The compiled program's outputs are bit-identical to the numpy
-    oracle on random occupancies and random request rows (denials from
-    saturated destinations included)."""
-    name = ("tall", "wide")[mesh_pick]
-    mesh, n = MESHES[name], N_SLOTS[name]
+    oracle on random occupancies, from nearly free to saturated, and
+    random request rows: denials from saturated destinations included,
+    and rows whose source is their destination (an INIT's shape)."""
+    mesh, n = ORACLE_MESHES[mesh_pick]
     rng = np.random.default_rng(seed)
-    # Random pre-existing circuits fill the table -> non-trivial occupancy.
-    warm = TdmAllocator(mesh, n)
-    warm.allocate_batch(_stream(rng, mesh, 48), cycle=0)
-    occ = warm.table.busy_masks((0 + 3) // n)
+    bits = rng.random((mesh.n_nodes, 7, n)) < density
+    occ = (bits * (np.uint64(1) << np.arange(n, dtype=np.uint64))) \
+        .sum(-1).astype(np.uint32)
     B = 32
     srcs = rng.integers(mesh.n_nodes, size=B).astype(np.int64)
-    dsts = (srcs + 1 + rng.integers(mesh.n_nodes - 1, size=B)) % mesh.n_nodes
+    dsts = rng.integers(mesh.n_nodes, size=B).astype(np.int64)
+    same = rng.random(B) < 0.25
+    dsts[same] = srcs[same]
     t_readys = rng.integers(3, 50, size=B).astype(np.int64)
     got = fused_mod.fused_prepare(occ, srcs, dsts, t_readys, mesh=mesh,
                                   n_slots=n)

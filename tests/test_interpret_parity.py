@@ -1,9 +1,8 @@
 """Compiled vs interpret parity for every Pallas kernel in the repo.
 
-Each kernel family (slot_alloc wavefront + slot scoring, flash_attention,
-ssd_scan, rglru_scan) is run twice on identical inputs — once with
-``interpret=True`` and once with ``interpret=False`` — and the outputs
-must match bit-for-bit.  On backends where compiled Pallas is not
+Each kernel family (flash_attention, ssd_scan, rglru_scan) is run twice
+on identical inputs — once with ``interpret=True`` and once with
+``interpret=False`` — and the outputs must match bit-for-bit.  On backends where compiled Pallas is not
 available (CPU raises ``ValueError: Only interpret mode is supported on
 CPU backend.``), the parity half SKIPS with the refusal recorded in the
 skip reason, so a CI log always shows *why* compiled mode wasn't proven.
@@ -19,14 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.slot_alloc import TdmAllocator, wavefront_search_batch
-from repro.core.topology import Mesh3D
 from repro.kernels.interpret import default_interpret, resolve_interpret
-from repro.kernels.slot_alloc import fused as fused_mod
-from repro.kernels.slot_alloc.ops import wavefront_search_pallas_batch
-
-MESH = Mesh3D(4, 4, 2, vault_span_y=1)
-N_SLOTS = 8
 
 
 def _compiled(label, fn, *args, **kwargs):
@@ -71,57 +63,8 @@ def test_kernels_run_with_no_interpret_argument():
     A = jnp.full((2,), -1.0, jnp.float32)
     assert ssd_scan(x, dt, B, B, A, chunk=16).shape == x.shape
 
-    occ = np.zeros((MESH.n_nodes, 7), np.uint32)
-    srcs, dsts = np.asarray([0, 3]), np.asarray([9, 21])
-    init = np.zeros(2, np.uint32)
-    out = wavefront_search_pallas_batch(occ, srcs, dsts, init, mesh=MESH,
-                                        n_slots=N_SLOTS)
-    np.testing.assert_array_equal(
-        np.asarray(out),
-        np.asarray(wavefront_search_batch(occ, srcs, dsts, init, mesh=MESH,
-                                          n_slots=N_SLOTS)))
-
 
 # --- per-kernel compiled/interpret parity ------------------------------------
-def _warm_occupancy():
-    rng = np.random.default_rng(3)
-    warm = TdmAllocator(MESH, N_SLOTS)
-    for _ in range(24):
-        s, d = (int(v) for v in rng.integers(MESH.n_nodes, size=2))
-        if s != d:
-            warm.allocate(s, d, 512, cycle=0)
-    return warm.table.busy_masks(0)
-
-
-def test_slot_alloc_wavefront_parity():
-    occ = _warm_occupancy()
-    rng = np.random.default_rng(4)
-    B = 16
-    srcs = rng.integers(MESH.n_nodes, size=B)
-    dsts = (srcs + 1 + rng.integers(MESH.n_nodes - 1, size=B)) % MESH.n_nodes
-    init = np.zeros(B, np.uint32)
-    interp = np.asarray(wavefront_search_pallas_batch(
-        occ, srcs, dsts, init, mesh=MESH, n_slots=N_SLOTS, interpret=True))
-    comp = np.asarray(_compiled(
-        "slot_alloc/wavefront", wavefront_search_pallas_batch,
-        occ, srcs, dsts, init, mesh=MESH, n_slots=N_SLOTS))
-    np.testing.assert_array_equal(comp, interp)
-
-
-def test_slot_alloc_slot_score_parity():
-    rng = np.random.default_rng(5)
-    avail = jnp.asarray(rng.integers(0, 2**N_SLOTS, size=24), jnp.uint32)
-    planes = fused_mod.unpack_bits(avail, N_SLOTS)
-    dists = jnp.asarray(rng.integers(0, 9, size=24), jnp.int32)
-    t = jnp.asarray(rng.integers(0, 30, size=24), jnp.int32)
-    interp = np.asarray(fused_mod.slot_score_planes(
-        planes, dists, t, n_slots=N_SLOTS, interpret=True))
-    comp = np.asarray(_compiled(
-        "slot_alloc/slot_score", fused_mod.slot_score_planes,
-        planes, dists, t, n_slots=N_SLOTS))
-    np.testing.assert_array_equal(comp, interp)
-
-
 def test_flash_attention_parity():
     from repro.kernels.flash_attention.ops import flash_attention
     rng = np.random.default_rng(6)
@@ -156,25 +99,3 @@ def test_rglru_scan_parity():
     interp = np.asarray(rglru_scan(a, b, chunk=16, interpret=True))
     comp = np.asarray(_compiled("rglru_scan", rglru_scan, a, b, chunk=16))
     np.testing.assert_array_equal(comp, interp)
-
-
-def test_fused_prepare_program_parity():
-    """The whole fused program under kernel="pallas": interpret on/off."""
-    occ = _warm_occupancy()
-    rng = np.random.default_rng(9)
-    B = 16
-    srcs = rng.integers(MESH.n_nodes, size=B).astype(np.int64)
-    dsts = (srcs + 1 + rng.integers(MESH.n_nodes - 1, size=B)) % MESH.n_nodes
-    t = rng.integers(3, 20, size=B).astype(np.int64)
-
-    def run(interpret):
-        return fused_mod.fused_prepare(occ, srcs, dsts, t, mesh=MESH,
-                                       n_slots=N_SLOTS, kernel="pallas",
-                                       interpret=interpret)
-
-    interp = run(True)
-    comp = _compiled("slot_alloc/fused", lambda *, interpret: run(interpret))
-    for field in ("starts", "arr", "dists", "denied", "ok",
-                  "hop_n", "hop_p", "hop_s"):
-        np.testing.assert_array_equal(getattr(comp, field),
-                                      getattr(interp, field), err_msg=field)

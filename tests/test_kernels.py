@@ -3,35 +3,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.slot_alloc import TdmAllocator
-from repro.core.topology import Mesh3D
-
 RNG = np.random.default_rng(42)
-
-
-# --- slot_alloc -------------------------------------------------------------
-@pytest.mark.parametrize("mesh_dims,n_slots", [((8, 8, 4), 16),
-                                               ((4, 4, 2), 8),
-                                               ((8, 8, 4), 32)])
-def test_slot_alloc_kernel_vs_ref(mesh_dims, n_slots):
-    from repro.kernels.slot_alloc.ops import wavefront_search_pallas_batch
-    from repro.kernels.slot_alloc.ref import wavefront_search_ref_batch
-    mesh = Mesh3D(*mesh_dims)
-    alloc = TdmAllocator(mesh, n_slots)
-    for i in range(12):
-        s, d = RNG.integers(mesh.n_nodes, size=2)
-        if s != d:
-            alloc.allocate(int(s), int(d), 256, cycle=i * 3)
-    occ = alloc.table.busy_masks(window=0)
-    B = 8
-    srcs = RNG.integers(mesh.n_nodes, size=B)
-    dsts = (srcs + 1 + RNG.integers(mesh.n_nodes - 1, size=B)) % mesh.n_nodes
-    inits = RNG.integers(0, 4, size=B).astype(np.uint32)
-    got = np.asarray(wavefront_search_pallas_batch(
-        occ, srcs, dsts, inits, mesh=mesh, n_slots=n_slots))
-    want = wavefront_search_ref_batch(occ, srcs, dsts, inits, mesh=mesh,
-                                      n_slots=n_slots)
-    np.testing.assert_array_equal(got, want)
 
 
 # --- flash attention -------------------------------------------------------

@@ -235,6 +235,7 @@ def test_windows_expire_and_slots_recycle():
 
 # --- the wavefront search against its scalar host twin ----------------------
 SEARCH_MESHES = {"8x8x4/16": (Mesh3D(8, 8, 4), 16),
+                 "8x8x4/32": (Mesh3D(8, 8, 4), 32),
                  "4x4x2/8": (Mesh3D(4, 4, 2), 8),
                  "1x4x2/8": (Mesh3D(1, 4, 2), 8),
                  "1x1x1/4": (Mesh3D(1, 1, 1, vault_span_y=1), 4)}

@@ -2,10 +2,9 @@
 
 The TPU compiler is installed with JAX and compiles for a chip that is
 described, not attached, so these tests refuse what the chip's compiler
-would refuse (unaligned Pallas blocks, too much fast memory, programs that
-do not fit the device) at no chip time.  Nothing runs: results are checked
-by the interpret-mode and differential tests, and on the chip by
-``chip_smoke.py``.
+would refuse (too much fast memory, programs that do not fit the device)
+at no chip time.  Nothing runs: results are checked by the differential
+tests, and on the chip by ``chip_smoke.py``.
 
 The topology is described inside a module fixture, never while a module
 is imported, so every pytest-xdist worker collects the same tests and only
@@ -27,10 +26,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.core.topology import make_topology
-from repro.kernels.slot_alloc.fused import (fused_prepare_program,
-                                            slot_score_planes)
-from repro.kernels.slot_alloc.slot_alloc import (LANES,
-                                                 wavefront_search_planes)
+from repro.kernels.slot_alloc.fused import fused_prepare_program
 from repro.models import make_model
 
 HBM_BYTES = 16e9            # one TPU v5e
@@ -77,38 +73,13 @@ def _compile(fn, *args):
     return compiled.as_text(), total
 
 
-@pytest.mark.parametrize("kernel", ["jnp", "pallas"])
-def test_fused_prepare_program_compiles(one_chip, kernel):
+def test_fused_prepare_program_compiles(one_chip):
     n = MESH.n_nodes
     req = _spec(one_chip, (BATCH,), jnp.int32)
     text, total = _compile(
-        partial(fused_prepare_program, mesh=MESH, n_slots=N_SLOTS,
-                kernel=kernel, interpret=False),
+        partial(fused_prepare_program, mesh=MESH, n_slots=N_SLOTS),
         _spec(one_chip, (n, N_PORTS), jnp.uint32), req, req, req)
-    assert ("tpu_custom_call" in text) == (kernel == "pallas")
-    assert total < HBM_BYTES
-
-
-def test_wavefront_search_planes_compiles(one_chip):
-    n = MESH.n_nodes
-    text, total = _compile(
-        partial(wavefront_search_planes,
-                mesh_shape=(MESH.X, MESH.Y, MESH.Z), n_slots=N_SLOTS,
-                interpret=False),
-        _spec(one_chip, (BATCH, 3), jnp.int32),
-        _spec(one_chip, (BATCH, 3, n), jnp.int32),
-        _spec(one_chip, (BATCH, n, LANES), jnp.int32),
-        _spec(one_chip, (6, n, LANES), jnp.int32))
-    assert "tpu_custom_call" in text
-    assert total < HBM_BYTES
-
-
-def test_slot_score_planes_compiles(one_chip):
-    req = _spec(one_chip, (BATCH,), jnp.int32)
-    text, total = _compile(
-        partial(slot_score_planes, n_slots=N_SLOTS, interpret=False),
-        _spec(one_chip, (BATCH, LANES), jnp.int32), req, req)
-    assert "tpu_custom_call" in text
+    assert "tpu_custom_call" not in text
     assert total < HBM_BYTES
 
 
